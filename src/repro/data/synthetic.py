@@ -5,8 +5,10 @@ but with STRUCTURE, not iid noise, so models measurably learn:
 
 * SyntheticLM  — a Markov token stream with per-sequence latent "topics":
   next-token distribution is a mixture of a global bigram table and a
-  topic-specific unigram boost. CE should fall well below log(V) when the
-  model learns the bigram structure (integration tests assert this).
+  topic-specific unigram boost. Bigram rows are drawn per previous token,
+  never materialized as a (V, V) table, so full vocabularies fit. CE
+  should fall well below log(V) when the model learns the bigram
+  structure (integration tests assert this).
 * SyntheticVision — class-conditional patch prototypes + noise (the
   ViT fine-tuning stand-in for CIFAR-style tasks).
 * SyntheticAudio — frame embeddings with class-dependent spectral envelope.
@@ -18,8 +20,9 @@ process_index (multi-host data loading without a distributed filesystem).
 """
 from __future__ import annotations
 
+import functools
 import zlib
-from dataclasses import dataclass, replace
+from dataclasses import dataclass, field, replace
 
 import jax
 import jax.numpy as jnp
@@ -39,50 +42,62 @@ class SyntheticLM:
     # everything a GLOBAL model learns — is shared across tenants.
     tenant: str | None = None
     tenant_offmix: float = 0.15
+    # topic table (n_topics, V), built once per stream in __post_init__
+    topic: jax.Array = field(init=False, repr=False, compare=False)
+
+    def __post_init__(self):
+        k_topic = jax.random.split(jax.random.PRNGKey(self.seed))[1]
+        object.__setattr__(self, "topic", jax.random.normal(
+            k_topic, (self.n_topics, self.vocab_size)) * 2.0)
 
     def for_tenant(self, uid: str) -> "SyntheticLM":
         """This stream, skewed toward tenant ``uid``'s topic. Deterministic
         in (seed, step, uid); ``uid=None``-equivalent is the base stream."""
         return replace(self, tenant=uid)
 
-    def _tables(self):
-        key = jax.random.PRNGKey(self.seed)
-        k1, k2 = jax.random.split(key)
-        # sparse-ish bigram logits
-        bigram = jax.random.normal(k1, (self.vocab_size, self.vocab_size)) * 2.0
-        topic = jax.random.normal(k2, (self.n_topics, self.vocab_size)) * 2.0
-        return bigram, topic
-
     def batch(self, step: int, batch_size: int | None = None) -> dict:
         """Batch for a global step: {tokens (B,S), labels (B,S)}."""
-        b = batch_size or self.global_batch
-        bigram, topic = self._tables()
-        key = jax.random.fold_in(jax.random.PRNGKey(self.seed + 1), step)
-        kt, ks, kc = jax.random.split(key, 3)
-        topics = jax.random.randint(kt, (b,), 0, self.n_topics)
-        if self.tenant is not None:
-            fav = zlib.crc32(self.tenant.encode()) % self.n_topics
-            km = jax.random.fold_in(kt, 1)
-            offmix = jax.random.uniform(km, (b,)) < self.tenant_offmix
-            topics = jnp.where(offmix, topics, fav)
-        start = jax.random.randint(ks, (b,), 0, self.vocab_size)
+        fav = -1 if self.tenant is None else \
+            zlib.crc32(self.tenant.encode()) % self.n_topics
+        return _markov_batch(self.seed, step, self.topic, fav,
+                             self.tenant_offmix,
+                             batch=batch_size or self.global_batch,
+                             seq_len=self.seq_len)
 
-        def gen_row(carry, k):
-            tok, tvec = carry
-            logits = bigram[tok] + tvec
-            nxt = jax.random.categorical(k, logits)
-            return (nxt, tvec), nxt
 
-        keys = jax.random.split(kc, self.seq_len * b).reshape(self.seq_len, b, 2)
+@functools.partial(jax.jit, static_argnames=("batch", "seq_len"))
+def _markov_batch(seed, step, topic, fav, offmix, *, batch: int,
+                  seq_len: int) -> dict:
+    """SyntheticLM's stream as one compiled program. The bigram row of the
+    previous token is drawn on the fly from a key folded with that token,
+    so each row has the distribution of a row of a dense (V, V) table
+    while memory stays O(B*V) — a dense table at V=151936 is 92 GB."""
+    n_topics, vocab = topic.shape
+    k_bigram = jax.random.split(jax.random.PRNGKey(seed))[0]
+    key = jax.random.fold_in(jax.random.PRNGKey(seed + 1), step)
+    kt, ks, kc = jax.random.split(key, 3)
+    topics = jax.random.randint(kt, (batch,), 0, n_topics)
+    offmix_rows = jax.random.uniform(jax.random.fold_in(kt, 1),
+                                     (batch,)) < offmix
+    topics = jnp.where((fav < 0) | offmix_rows, topics, fav)
+    start = jax.random.randint(ks, (batch,), 0, vocab)
 
-        def gen_seq(s0, tvec, kk):
-            (_, _), toks = jax.lax.scan(gen_row, (s0, tvec), kk)
-            return toks
+    def gen_row(carry, k):
+        tok, tvec = carry
+        row = jax.random.normal(jax.random.fold_in(k_bigram, tok), (vocab,))
+        nxt = jax.random.categorical(k, row * 2.0 + tvec)
+        return (nxt, tvec), nxt
 
-        toks = jax.vmap(gen_seq, in_axes=(0, 0, 1))(start, topic[topics], keys)
-        tokens = jnp.concatenate([start[:, None], toks[:, :-1]], axis=1)
-        return {"tokens": tokens.astype(jnp.int32),
-                "labels": toks.astype(jnp.int32)}
+    keys = jax.random.split(kc, seq_len * batch).reshape(seq_len, batch, 2)
+
+    def gen_seq(s0, tvec, kk):
+        (_, _), toks = jax.lax.scan(gen_row, (s0, tvec), kk)
+        return toks
+
+    toks = jax.vmap(gen_seq, in_axes=(0, 0, 1))(start, topic[topics], keys)
+    tokens = jnp.concatenate([start[:, None], toks[:, :-1]], axis=1)
+    return {"tokens": tokens.astype(jnp.int32),
+            "labels": toks.astype(jnp.int32)}
 
 
 @dataclass(frozen=True)

@@ -2,8 +2,8 @@
 
 ``shard_map`` — one compat alias every mesh consumer (train/step.py, the
 parity tests, this module) imports, so the jax.shard_map ->
-jax.experimental.shard_map rename difference across jax versions lives in
-exactly one place.
+jax.experimental.shard_map rename (and check_rep -> check_vma) across jax
+versions lives in exactly one place.
 
 ``collective_bytes`` / ``measured_collective_bytes`` — the MEASURED side of
 the communication story: parse the post-SPMD HLO of a compiled executable
@@ -31,10 +31,13 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.5 exposes it at the top level
+try:  # newer jax: top level, its replication check is named check_vma
     shard_map = jax.shard_map
-except AttributeError:  # 0.4.x
-    from jax.experimental.shard_map import shard_map
+except AttributeError:  # 0.4.x: the same check is named check_rep
+    from jax.experimental.shard_map import shard_map as _shard_map
+
+    def shard_map(f, *, check_vma: bool = True, **kw):
+        return _shard_map(f, check_rep=check_vma, **kw)
 
 NEG_INF = -1e30
 
@@ -62,18 +65,42 @@ def _shape_bytes(m) -> int:
     return n * BYTES[dt]
 
 
+def _tuple_elements(t: str) -> list[str]:
+    """Top-level elements of an HLO tuple type "(a, (b, c), d)"."""
+    parts, depth, start = [], 0, 1
+    for i, ch in enumerate(t):
+        if ch in "([{":
+            depth += 1
+        elif ch in ")]}":
+            depth -= 1
+        elif ch == "," and depth == 1:
+            parts.append(t[start:i])
+            start = i + 1
+    parts.append(t[start:t.rindex(")")])
+    return parts
+
+
 def collective_bytes(hlo_text: str) -> dict[str, int]:
-    """Sum RESULT sizes of collective ops in post-SPMD HLO (per device)."""
+    """Sum RESULT sizes of collective ops in post-SPMD HLO (per device).
+
+    A combined op's result is a tuple, "(f32[a], f32[b]) all-reduce(...)",
+    and every element counts. The async start of any collective but
+    all-reduce returns (operand, result, context...): only its result
+    element counts, itself a tuple when the op is combined."""
     out = {c: 0 for c in COLLECTIVES}
     out["count"] = 0
     for line in hlo_text.splitlines():
         ls = line.strip()
         for c in COLLECTIVES:
-            # match op lines: "%x = TYPE[dims] all-reduce(...)" (incl. -start)
-            if re.search(rf"\b{c}(-start)?\(", ls):
-                m = DTYPE_RE.search(ls)
-                if m:
-                    out[c] += _shape_bytes(m)
+            # match op lines: "%x = TYPE all-reduce(...)" (incl. -start)
+            op = re.search(rf"\b{c}(-start)?\(", ls)
+            if op:
+                result = ls[ls.find("=") + 1:op.start()].strip()
+                if op.group(1) and c != "all-reduce" and result[:1] == "(":
+                    result = _tuple_elements(result)[1]
+                sizes = [_shape_bytes(m) for m in DTYPE_RE.finditer(result)]
+                if sizes:
+                    out[c] += sum(sizes)
                     out["count"] += 1
                 break
     out["total"] = sum(out[c] for c in COLLECTIVES)

@@ -1,9 +1,12 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-``interpret`` defaults to True because this host is CPU-only (TPU v5e is
-the compile TARGET); on a real TPU runtime set
-``repro.kernels.ops.INTERPRET = False`` (launcher does this when
-jax.default_backend() == 'tpu').
+The route is chosen once, from the backend, when this module is imported:
+``INTERPRET`` is False exactly when ``jax.default_backend() == "tpu"``.
+On TPU the wrappers launch the compiled kernels; on any other backend they
+take the einsum fallbacks, or run a kernel in Pallas interpret mode where
+no fallback exists. No launcher sets it. A TPU that fails to initialise
+leaves JAX on the CPU and this flag True, so ``chip_smoke.py`` asserts it
+is False before it measures anything.
 """
 from __future__ import annotations
 

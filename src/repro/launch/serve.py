@@ -27,6 +27,7 @@ import jax.numpy as jnp
 
 import repro.configs as configs
 from repro import api
+from repro.launch.cache import use_compile_cache
 from repro.models.lm import init_lm, init_lm_cache, lm_decode_step, lm_prefill
 from repro.serve import SCHEDULERS, EventKind, SamplingParams, ServeEngine
 
@@ -174,6 +175,7 @@ def main():
                          "batch axis; 0 = single device). Excludes "
                          "--paged/--spec-k/--adapters")
     args = ap.parse_args()
+    use_compile_cache()
 
     key = jax.random.PRNGKey(0)
     slots = args.max_slots or min(args.batch, 4)

@@ -30,6 +30,7 @@ from repro import api
 from repro.checkpoint import CheckpointManager, latest_step, restore_extra
 from repro.config import TrainConfig
 from repro.data.registry import make_dataset
+from repro.launch.cache import use_compile_cache
 from repro.train.loop import READER_EXTRA, train_loop
 from repro.train.step import (
     dp_batch_sharding,
@@ -152,6 +153,7 @@ def main():
     ap.add_argument("--print-plan", action="store_true",
                     help="print the resolved SubspacePlan and exit")
     args = ap.parse_args()
+    use_compile_cache()
 
     tcfg = TrainConfig(optimizer=args.optimizer, lr=args.lr, steps=args.steps,
                        checkpoint_every=args.ckpt_every,

@@ -41,9 +41,14 @@ Design (the TrainDeeploy lesson: kernel and serving loop co-designed):
   weights (f32 or int8 factors) replicated on every device, the KV slot
   pool sharded across devices on the cache BATCH axis, so `max_slots`
   scales with the mesh while every executable stays
-  one-per-bucket. Each slot's decode math is row-independent, so mesh
-  generations are bitwise-identical to the single-device dense engine
-  (tests/test_mesh_parity.py pins this, f32 and int8). Paged pools,
+  one-per-bucket. The steps are per-device programs under shard_map
+  (XLA cannot partition a Pallas kernel): decode runs each device's own
+  slots, and prefill runs an admitted group on every device, each keeping
+  the rows it holds. Each slot's decode math is row-independent, so on
+  CPU mesh generations are bitwise-identical to the single-device dense
+  engine (tests/test_mesh_parity.py pins this, f32 and int8). On TPU the
+  per-device programs see fewer rows than the single-device one and may
+  round differently, so greedy tokens can part at a near-tie. Paged pools,
   speculative decoding and tenant adapters keep their single-device
   engines for now — mesh serves the dense oracle path.
 
@@ -332,15 +337,41 @@ class ServeEngine:
                       "spec_accepted_tokens": 0, "spec_page_shrinks": 0,
                       "adapter_evictions": 0}
 
-        def _pin(caches):
-            # mesh mode: keep the returned cache pytree sharded on the slot
-            # axis — without the constraint the row gather/scatter in
-            # prefill can make XLA fall back to a replicated layout
-            if mesh is None:
-                return caches
-            return jax.tree.map(
-                lambda c: jax.lax.with_sharding_constraint(
-                    c, self._cache_shard), caches)
+        def _gather_rows(caches, rows):
+            return jax.tree.map(lambda a: a[:, rows], caches)
+
+        def _scatter_rows(caches, rows, sub):
+            return jax.tree.map(lambda g, l: g.at[:, rows].set(l), caches,
+                                sub)
+
+        if mesh is not None:
+            # under shard_map (below) each device holds `per` slots and an
+            # admitted group's rows may lie on any device: gathering sums
+            # the rows each device holds (exact — every other term is
+            # zero), and scattering keeps only this device's rows
+            axes = tuple(mesh.axis_names)
+            per = max_slots // mesh.devices.size
+
+            def _local(rows):
+                local = rows - jax.lax.axis_index(axes) * per
+                return local, (local >= 0) & (local < per)
+
+            def _gather_rows(caches, rows):
+                local, mine = _local(rows)
+                idx = jnp.where(mine, local, 0)
+
+                def gather(a):
+                    m = mine.reshape((1, -1) + (1,) * (a.ndim - 2))
+                    return jax.lax.psum(
+                        jnp.where(m, a[:, idx], jnp.zeros((), a.dtype)), axes)
+                return jax.tree.map(gather, caches)
+
+            def _scatter_rows(caches, rows, sub):
+                local, mine = _local(rows)
+                idx = jnp.where(mine, local, per)
+                return jax.tree.map(
+                    lambda g, l: g.at[:, idx].set(l, mode="drop"), caches,
+                    sub)
 
         def _merged(params_, banks, aix):
             # trace-time branch: a no-adapter engine passes banks=None and
@@ -360,21 +391,20 @@ class ServeEngine:
                                             toks, caches, pos, cfg,
                                             page_table=table)
             nxt = sample_tokens(logits, temp, tk, tp, seeds, counts)
-            return nxt, _pin(caches)
+            return nxt, caches
 
         def _prefill(params_, banks, aix, toks, caches, valid_len, rows,
                      temp, tk, tp, seeds):
             # dense grouped prefill: gather the admitted rows, prefill them
             # as one batch, scatter back — cache leaves are (repeat, B, ...),
             # batch on axis 1. `aix` is already row-gathered on the host.
-            sub = jax.tree.map(lambda a: a[:, rows], caches)
+            sub = _gather_rows(caches, rows)
             logits, sub = lm_prefill(_merged(params_, banks, aix), toks,
                                      cfg, caches=sub,
                                      valid_len=valid_len, last_only=True)
-            new = jax.tree.map(lambda g, l: g.at[:, rows].set(l), caches, sub)
             first = sample_tokens(logits[:, 0], temp, tk, tp, seeds,
                                   jnp.zeros_like(seeds, jnp.int32))
-            return first, _pin(new)
+            return first, _scatter_rows(caches, rows, sub)
 
         def _prefill_chunk(params_, banks, aix, toks, caches, offset,
                            valid_len, table, temp, tk, tp, seeds):
@@ -426,6 +456,22 @@ class ServeEngine:
         # adapter-aware steps carry caches at arg 4 (after banks + aix —
         # banks are NOT donated, they persist across calls); the spec
         # steps keep the original signature, caches at arg 2.
+        if mesh is not None:
+            # XLA cannot partition a Pallas kernel, so the mesh steps are
+            # per-device programs: decode runs each device's own slots,
+            # prefill runs the whole admitted group on every device
+            from repro.distributed.collectives import shard_map
+            rep, row, kv = P(), P(axes), P(None, axes)
+            _decode = shard_map(
+                _decode, mesh=mesh,
+                in_specs=(rep, rep, rep, row, kv, row, rep,
+                          row, row, row, row, row),
+                out_specs=(row, kv), check_vma=False)
+            _prefill = shard_map(
+                _prefill, mesh=mesh,
+                in_specs=(rep, rep, rep, rep, kv, rep, rep,
+                          rep, rep, rep, rep),
+                out_specs=(rep, kv), check_vma=False)
         cpu = jax.default_backend() == "cpu"
         donate = () if cpu else (4,)
         donate_spec = () if cpu else (2,)
@@ -872,13 +918,7 @@ class ServeEngine:
             table = jnp.asarray(tbl)
         else:
             table = None
-        banks, aix = self._adapter_args()
-        nxt, self.caches = self._decode(
-            self.params, banks, aix, jnp.asarray(self.next_tok[:, None]),
-            self.caches, jnp.asarray(self.pos), table,
-            jnp.asarray(self.temp), jnp.asarray(self.top_k),
-            jnp.asarray(self.top_p), jnp.asarray(self.seed),
-            jnp.asarray(self.count))
+        nxt, self.caches = self._decode(*self._decode_args(table))
         nxt = np.asarray(nxt, np.int32)
         self.stats["decode_steps"] += 1
         now = time.perf_counter()
@@ -891,6 +931,19 @@ class ServeEngine:
             self.stats["decode_tokens"] += 1
             self._finish_if_done(slot)
         self.stats["decode_s"] += time.perf_counter() - t0
+
+    def compiled_decode(self):
+        """The compiled program a dense decode tick runs for the current
+        slot state (``.as_text()`` shows which kernels it launches)."""
+        return self._decode.lower(*self._decode_args()).compile()
+
+    def _decode_args(self, table=None) -> tuple:
+        banks, aix = self._adapter_args()
+        return (self.params, banks, aix, jnp.asarray(self.next_tok[:, None]),
+                self.caches, jnp.asarray(self.pos), table,
+                jnp.asarray(self.temp), jnp.asarray(self.top_k),
+                jnp.asarray(self.top_p), jnp.asarray(self.seed),
+                jnp.asarray(self.count))
 
     def _spec_pages(self, active: list[int]) -> np.ndarray:
         """Paged-mode draft coverage: per-slot draft length after making

@@ -159,7 +159,7 @@ def make_train_step(loss_fn, cfg: ModelConfig, tcfg: TrainConfig, *,
         return shard_map(local_step, mesh=mesh,
                          in_specs=(sspecs, bspecs),
                          out_specs=(sspecs, P()),
-                         check_rep=False)(state, batch)
+                         check_vma=False)(state, batch)
 
     return dp_step
 

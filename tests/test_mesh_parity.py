@@ -150,7 +150,7 @@ def test_factor_allreduce_equals_dense_allreduce(mesh8):
         return jax.lax.pmean(dl[0] @ R + L @ dr[0], "data")
 
     sm = dict(mesh=mesh8, in_specs=(P("data"), P("data")), out_specs=P(),
-              check_rep=False)
+              check_vma=False)
     dl_m, dr_m = shard_map(factors, **sm)(dL, dR)
     dw_dense = shard_map(dense, **sm)(dL, dR)
 
@@ -200,7 +200,7 @@ def test_powersgd_dp_matches_single_device_oracle(mesh8):
     round_fn = shard_map(
         local, mesh=mesh8,
         in_specs=(P("data"), P(), P("data")),
-        out_specs=(P(), P(), P("data")), check_rep=False)
+        out_specs=(P(), P(), P("data")), check_vma=False)
 
     q, err = dp.q, dp.error
     for t in range(steps):
