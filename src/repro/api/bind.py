@@ -42,6 +42,7 @@ from repro.core.lowrank_linear import (
     wsi_matmul_project_exact,
 )
 from repro.core.wsi import WSIState
+from repro.utils.scopes import MATMUL
 
 
 # ---------------------------------------------------------------------------
@@ -147,13 +148,14 @@ def apply(spec: LinearSpec, p: dict, x: jax.Array, wasi: WasiConfig,
             y = wasi_matmul(x, p["L"], p["R"], xt)
         else:
             # no-ASI factored path (serving, `wsi` factored training)
-            if spec.kernel == "fused_lowrank":
-                # fused Pallas kernel on TPU, XLA einsum pair elsewhere
-                from repro.kernels.ops import lowrank_matmul
-                y = lowrank_matmul(x, p["R"], p["L"])
-            else:
-                h = jnp.einsum("...i,ki->...k", x, p["R"])
-                y = jnp.einsum("...k,ok->...o", h, p["L"])
+            with jax.named_scope(MATMUL):
+                if spec.kernel == "fused_lowrank":
+                    # fused Pallas kernel on TPU, XLA einsum pair elsewhere
+                    from repro.kernels.ops import lowrank_matmul
+                    y = lowrank_matmul(x, p["R"], p["L"])
+                else:
+                    h = jnp.einsum("...i,ki->...k", x, p["R"])
+                    y = jnp.einsum("...k,ok->...o", h, p["L"])
     else:
         # dense weights (vanilla, ASI baseline, or un-injected project)
         if state is not None:

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.orthogonal import cholesky_qr
+from repro.utils.scopes import ASI_UPKEEP, scoped
 
 
 class TuckerFactors(NamedTuple):
@@ -95,6 +96,7 @@ def _orth_last(v: jax.Array, shift: float = 1e-6) -> jax.Array:
     return jnp.einsum("...r,jr->...j", vf, inv)
 
 
+@scoped(ASI_UPKEEP)
 def asi_project(a: jax.Array, state: ASIState) -> TuckerFactors:
     """Project ``a`` onto the EXISTING factors (no power iteration) — the
     cheap steady-state compression when refreshes are amortized."""
@@ -106,6 +108,7 @@ def asi_project(a: jax.Array, state: ASIState) -> TuckerFactors:
     return TuckerFactors(core=core, us=state.us)
 
 
+@scoped(ASI_UPKEEP)
 def asi_step(a: jax.Array, state: ASIState) -> tuple[TuckerFactors, ASIState]:
     """One warm-started subspace-iteration Tucker compression (Alg. 2).
 

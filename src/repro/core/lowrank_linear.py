@@ -49,6 +49,7 @@ from repro.core.asi import (
     flr_weight_grad_3d,
     flr_weight_grad_4d,
 )
+from repro.utils.scopes import GRAD, MATMUL, scoped
 
 
 def _flr(xt: TuckerFactors, dy: jax.Array) -> jax.Array:
@@ -60,6 +61,7 @@ def _flr(xt: TuckerFactors, dy: jax.Array) -> jax.Array:
     raise ValueError(f"f_LR supports 3D/4D activations, got ndim={dy.ndim}")
 
 
+@scoped(MATMUL)
 def _project_last_mode(xt: TuckerFactors, r: jax.Array) -> TuckerFactors:
     """Tucker form of (x~ contracted with R^T on the feature mode):
     replace last factor U_I (I, r_m) by R @ U_I (K, r_m). If the feature
@@ -74,6 +76,7 @@ def _project_last_mode(xt: TuckerFactors, r: jax.Array) -> TuckerFactors:
 # ---------------------------------------------------------------------------
 
 @jax.custom_vjp
+@scoped(MATMUL)
 def wasi_matmul(x: jax.Array, L: jax.Array, R: jax.Array, xt: TuckerFactors):
     """y = (x @ R^T) @ L^T with Tucker residuals. x: (..., I) -> (..., O)."""
     h = jnp.einsum("...i,ki->...k", x, R)
@@ -92,6 +95,7 @@ def _wasi_fwd(x, L, R, xt):
     return y, (xt, ht, L, R)
 
 
+@scoped(GRAD)
 def _wasi_bwd(res, dy):
     xt, ht, L, R = res
     dh = jnp.einsum("...o,ok->...k", dy, L)            # (B,N,K)
@@ -112,6 +116,7 @@ wasi_matmul.defvjp(_wasi_fwd, _wasi_bwd)
 # ---------------------------------------------------------------------------
 
 @jax.custom_vjp
+@scoped(MATMUL)
 def asi_matmul(x: jax.Array, w: jax.Array, xt: TuckerFactors):
     """y = x @ W^T with Tucker residuals. w: (O, I)."""
     return jnp.einsum("...i,oi->...o", x, w)
@@ -121,6 +126,7 @@ def _asi_fwd(x, w, xt):
     return asi_matmul(x, w, xt), (xt, w)
 
 
+@scoped(GRAD)
 def _asi_bwd(res, dy):
     xt, w = res
     dx = jnp.einsum("...o,oi->...i", dy, w)
@@ -137,6 +143,7 @@ asi_matmul.defvjp(_asi_fwd, _asi_bwd)
 # ---------------------------------------------------------------------------
 
 @jax.custom_vjp
+@scoped(MATMUL)
 def wasi_matmul_project(x, w, L, R, xt: TuckerFactors):
     """Forward uses the factors; gradient lands on the full W (Eq. 11).
     L, R are derived from W by WSI *outside* the step (non-trainable here)."""
@@ -148,6 +155,7 @@ def _wasi_proj_fwd(x, w, L, R, xt):
     return wasi_matmul_project(x, w, L, R, xt), (xt, L, R)
 
 
+@scoped(GRAD)
 def _wasi_proj_bwd(res, dy):
     xt, L, R = res
     dx = jnp.einsum("...o,ok,ki->...i", dy, L, R)       # Eq. 10
@@ -160,6 +168,7 @@ wasi_matmul_project.defvjp(_wasi_proj_fwd, _wasi_proj_bwd)
 
 
 @jax.custom_vjp
+@scoped(MATMUL)
 def wsi_matmul_project_exact(x, w, L, R):
     """Project mode without activation compression (WSI ablation): factored
     forward, EXACT dense gradient dW = dy^T x (residual: uncompressed x)."""
@@ -171,6 +180,7 @@ def _wsi_proj_exact_fwd(x, w, L, R):
     return wsi_matmul_project_exact(x, w, L, R), (x, L, R)
 
 
+@scoped(GRAD)
 def _wsi_proj_exact_bwd(res, dy):
     x, L, R = res
     dx = jnp.einsum("...o,ok,ki->...i", dy, L, R)

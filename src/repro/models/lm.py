@@ -35,6 +35,7 @@ from repro.models.blocks import (
 )
 from repro.nn.attention import init_attention
 from repro.nn.norms import apply_norm, init_norm
+from repro.utils.scopes import EMBED, HEAD, scoped
 
 
 def _needs_shared(cfg: ModelConfig) -> bool:
@@ -209,6 +210,14 @@ def lm_backbone(params, x, cfg: ModelConfig, *, states=None, caches=None,
         (new_caches if caches is not None else None), aux_total
 
 
+@scoped(EMBED)
+def _embed(params, tokens, cfg: ModelConfig):
+    """Rows of the embedding for ``tokens``, in the model's dtype."""
+    return params["embed"]["w"].astype(jnp.float32)[tokens].astype(
+        jnp.dtype(cfg.dtype))
+
+
+@scoped(HEAD)
 def _logits(params, x, cfg: ModelConfig, policy):
     head = params["embed"]["w"] if cfg.tie_embeddings else params["lm_head"]["w"]
     logits = jnp.einsum("bsd,vd->bsv", x, head)
@@ -227,8 +236,7 @@ def lm_forward(params, tokens, cfg: ModelConfig, *, states=None, caches=None,
     if jnp.issubdtype(tokens.dtype, jnp.floating):
         x = tokens.astype(jnp.dtype(cfg.dtype))
     else:
-        x = params["embed"]["w"].astype(jnp.float32)[tokens].astype(
-            jnp.dtype(cfg.dtype))
+        x = _embed(params, tokens, cfg)
     x = shard(x, policy, "batch", "seq", None)
     x, ns, nc, aux = lm_backbone(params, x, cfg, states=states, caches=caches,
                                  pos=pos, policy=policy)
@@ -259,8 +267,7 @@ def lm_decode_step(params, token, caches, pos, cfg: ModelConfig, *,
     ``page_table`` (B, pages_per_slot) routes reads/writes through the
     paged KV pool when ``caches`` came from ``init_lm_cache(..., pages=)``.
     Returns (logits (B, V), new_caches)."""
-    x = params["embed"]["w"].astype(jnp.float32)[token].astype(
-        jnp.dtype(cfg.dtype))
+    x = _embed(params, token, cfg)
     x, _, nc, _ = lm_backbone(params, x, cfg, states=None, caches=caches,
                               pos=pos, policy=policy, page_table=page_table)
     return _logits(params, x, cfg, policy)[:, 0], nc
@@ -305,8 +312,7 @@ def lm_prefill(params, tokens, cfg: ModelConfig, *, caches,
     (accepted positions land exact full-precision KV; rejected tail
     positions are overwritten before they are ever attended to).
     """
-    x = params["embed"]["w"].astype(jnp.float32)[tokens].astype(
-        jnp.dtype(cfg.dtype))
+    x = _embed(params, tokens, cfg)
     x = shard(x, policy, "batch", "seq", None)
     x, _, nc, _ = lm_backbone(params, x, cfg, states=None, caches=caches,
                               pos=0 if pos is None else pos, policy=policy,

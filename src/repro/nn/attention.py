@@ -27,6 +27,7 @@ from repro.config import ModelConfig
 from repro.distributed.sharding import MeshPolicy, shard
 from repro.api import bind, plan_of, role_treated
 from repro.nn.rotary import apply_rope
+from repro.utils.scopes import ATTENTION, scoped
 
 NEG_INF = -1e30
 
@@ -118,6 +119,7 @@ def _mask_bias(sq: int, sk: int, q_offset, *, causal: bool,
     return jnp.where(ok, 0.0, NEG_INF).astype(jnp.float32)
 
 
+@scoped(ATTENTION)
 def dense_attention(q, k, v, *, causal: bool, window: int = 0,
                     q_offset=0) -> jax.Array:
     """Reference attention materializing scores. q (B,Sq,H,Dh)."""
@@ -132,6 +134,7 @@ def dense_attention(q, k, v, *, causal: bool, window: int = 0,
     return o.reshape(b, sq, h, dh)
 
 
+@scoped(ATTENTION)
 def chunked_attention(q, k, v, *, causal: bool, window: int = 0,
                       q_offset=0, chunk: int = 1024,
                       q_chunk: int = 1024) -> jax.Array:
@@ -229,6 +232,7 @@ def is_vector_pos(pos) -> bool:
     return hasattr(pos, "ndim") and pos.ndim == 1
 
 
+@scoped(ATTENTION)
 def decode_attention(q, cache: KVCache, pos, *, window: int = 0) -> jax.Array:
     """Single-token decode. q (B,1,H,Dh); cache holds positions <= pos.
 
@@ -338,6 +342,7 @@ def cache_update_verify(cache: KVCache, k_new, v_new, offset,
                    v=cache.v.at[rows, pos].set(v_new, mode="drop"))
 
 
+@scoped(ATTENTION)
 def dense_verify_attention(q, cache: KVCache, qpos):
     """Token-parallel attention over a row's ENTIRE dense cache: q
     (B, C, H, Dh) at absolute positions ``qpos`` (B, C), masked causally
@@ -419,6 +424,7 @@ def paged_update_prefill(cache: PagedKVCache, k_new, v_new, offset,
                         v=cache.v.at[pid, off].set(v_new))
 
 
+@scoped(ATTENTION)
 def paged_prefill_attention(q, cache: PagedKVCache, page_table, qpos):
     """Chunked-prefill attention: q (B, C, H, Dh) at absolute positions
     ``qpos`` (B, C) attends over the slot's ENTIRE logical cache (history

@@ -14,8 +14,11 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.utils.scopes import XENT, scoped
+
 
 @jax.custom_vjp
+@scoped(XENT)
 def masked_xent(logits: jax.Array, labels: jax.Array, mask: jax.Array):
     """Mean CE over mask>0 positions. logits (B,S,V); labels (B,S) int."""
     lf = logits.astype(jnp.float32)
@@ -24,6 +27,7 @@ def masked_xent(logits: jax.Array, labels: jax.Array, mask: jax.Array):
     return ((lse - gold) * mask).sum() / jnp.maximum(mask.sum(), 1.0)
 
 
+@scoped(XENT)
 def _fwd(logits, labels, mask):
     lf = logits.astype(jnp.float32)
     lse = jax.scipy.special.logsumexp(lf, axis=-1)
@@ -33,6 +37,7 @@ def _fwd(logits, labels, mask):
     return loss, (logits, lse, labels, mask, n)
 
 
+@scoped(XENT)
 def _bwd(res, g):
     logits, lse, labels, mask, n = res
     p = jnp.exp(logits.astype(jnp.float32) - lse[..., None])

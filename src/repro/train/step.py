@@ -51,6 +51,7 @@ from repro.optim import (
     make_schedule,
     optimizer_update,
 )
+from repro.utils.scopes import OPTIMIZER, WSI_REFRESH, scoped
 
 
 class TrainState(NamedTuple):
@@ -216,10 +217,11 @@ def _build_step(loss_fn, cfg: ModelConfig, tcfg: TrainConfig,
             if mean_fn is not None:
                 grads = jax.tree.map(mean_fn, grads)
 
-        grads, gnorm = clip_by_global_norm(grads, tcfg.clip_norm)
-        lr = schedule(state.step)
-        new_params, new_opt = optimizer_update(params, grads, state.opt,
-                                               tcfg, lr)
+        with jax.named_scope(OPTIMIZER):
+            grads, gnorm = clip_by_global_norm(grads, tcfg.clip_norm)
+            lr = schedule(state.step)
+            new_params, new_opt = optimizer_update(params, grads, state.opt,
+                                                   tcfg, lr)
 
         new_wsi = state.wsi
         if state.wsi is not None:
@@ -229,7 +231,8 @@ def _build_step(loss_fn, cfg: ModelConfig, tcfg: TrainConfig,
             do = (state.step + 1) % cfg.wasi.refresh_every == 0
             new_params = jax.lax.cond(
                 do,
-                lambda p: map_factored(p, wsi_refresh_factored),
+                scoped(WSI_REFRESH)(
+                    lambda p: map_factored(p, wsi_refresh_factored)),
                 lambda p: p,
                 new_params)
 
