@@ -19,7 +19,9 @@ Kernels:
   qr               — FUSED CholeskyQR: Gram -> in-kernel Cholesky/triangular
                      inverse -> apply, plus the Q^T Y mix matrix, one launch
                      (the WSI factored-refresh hot path)
-  flash_attention  — causal/sliding-window online-softmax attention
+  flash_attention  — causal/sliding-window grouped-query online-softmax
+                     attention with its own backward (dq and dkv
+                     kernels): the train step's attention core on TPU
   ssd_scan         — Mamba-2 SSD chunked scan with on-chip state carry
 
 See docs/kernels.md for grid/BlockSpec conventions and the interpret-mode

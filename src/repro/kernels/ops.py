@@ -15,12 +15,14 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.flash_attention import flash_attention_tiled
+from repro.kernels.flash_attention import (flash_attention_bwd,
+                                           flash_attention_fwd)
 from repro.kernels.gram import gram_tiled
 from repro.kernels.lowrank import lowrank_bwd_tiled, lowrank_fused_tiled
 from repro.kernels.matmul_tiled import matmul_tiled
 from repro.kernels.qr import choleskyqr_tiled
 from repro.kernels.quant import lowrank_q8_tiled
+from repro.utils.scopes import ATTENTION, scoped
 
 INTERPRET = jax.default_backend() != "tpu"
 
@@ -28,6 +30,10 @@ INTERPRET = jax.default_backend() != "tpu"
 # all five operand tiles plus the two (O,K)/(K,I) f32 accumulators must
 # co-reside. Larger layers fall back to the XLA einsum backward.
 _BWD_VMEM_BUDGET = 12 * 1024 * 1024
+
+# Largest flash attention tile, query and key alike; chosen by timing the
+# kernels on a TPU v5e (PERF.md).
+FLASH_BLOCK = 1024
 
 
 def _bwd_fits_vmem(m: int, o: int, i: int, k: int, bm: int = 128) -> bool:
@@ -204,33 +210,82 @@ def cholesky_qr_mix(y):
     return choleskyqr_fused(y)
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "window", "bq", "bk"))
-def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    bq: int = 128, bk: int = 128):
-    """GQA flash attention. q (B, Sq, H, dh); k/v (B, Sk, KVH, dh).
+def _flash_tiles(s: int) -> tuple[int, int, int]:
+    """(padded length, query tile, key tile) for a sequence of ``s``
+    tokens: one tile up to ``FLASH_BLOCK``, else the largest power-of-two
+    multiple of 128 up to it that divides ``s`` rounded up to 128."""
+    if s <= FLASH_BLOCK:
+        p = -(-s // 8) * 8
+        return p, p, p
+    p = -(-s // 128) * 128
+    t = FLASH_BLOCK
+    while p % t:
+        t //= 2
+    return p, t, t
 
-    KV heads are expanded to H by index (gather, no copy through the MXU),
-    heads folded into the batch grid dim, dh padded to a lane multiple.
+
+def _heads_major(x, s_pad: int):
+    """(B, S, heads, D) -> (B, heads, S_pad, D), zero rows appended."""
+    x = x.transpose(0, 2, 1, 3)
+    pad = s_pad - x.shape[2]
+    return jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0))) if pad else x
+
+
+def _seq_major(x, s: int):
+    return x[:, :, :s].transpose(0, 2, 1, 3)
+
+
+def _attention_mxu_dtype():
+    """Operand dtype of the flash kernel's matmuls: what XLA's DEFAULT
+    precision gives dense attention's f32 einsums on this backend, one
+    bf16 pass on TPU and f32 elsewhere."""
+    return jnp.float32 if INTERPRET else jnp.bfloat16
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash(q, k, v, causal, window, mxu_dtype):
+    return _flash_fwd(q, k, v, causal, window, mxu_dtype)[0]
+
+
+@scoped(ATTENTION)
+def _flash_fwd(q, k, v, causal, window, mxu_dtype):
+    s = q.shape[1]
+    s_pad, bq, bk = _flash_tiles(s)
+    qt, kt, vt = (_heads_major(x, s_pad) for x in (q, k, v))
+    o, lse = flash_attention_fwd(qt, kt, vt, causal=causal, window=window,
+                                 s_valid=s, bq=bq, bk=bk, mxu_dtype=mxu_dtype,
+                                 interpret=INTERPRET)
+    return _seq_major(o, s), (qt, kt, vt, o, lse)
+
+
+@scoped(ATTENTION)
+def _flash_bwd(causal, window, mxu_dtype, res, do):
+    qt, kt, vt, o, lse = res
+    s = do.shape[1]
+    s_pad, bq, bk = _flash_tiles(s)
+    dot = _heads_major(do, s_pad)
+    # rowsum(dO * O) = rowsum(P * dP), the softmax gradient's shift; zero
+    # on padded rows, whose dO is zero
+    di = jnp.sum(dot.astype(jnp.float32) * o.astype(jnp.float32),
+                 axis=-1)[:, :, None, :]
+    dq, dk, dv = flash_attention_bwd(qt, kt, vt, dot, lse, di, causal=causal,
+                                     window=window, s_valid=s, bq=bq, bk=bk,
+                                     mxu_dtype=mxu_dtype, interpret=INTERPRET)
+    return _seq_major(dq, s), _seq_major(dk, s), _seq_major(dv, s)
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+def flash_attention(q, k, v, *, causal: bool = True, window: int = 0):
+    """GQA flash attention with its own backward pass. q (B, S, H, dh);
+    k/v (B, S, KVH, dh) with H a multiple of KVH -> (B, S, H, dh) in
+    q.dtype. Differentiable: the gradients leave in q/k/v's dtypes.
+
+    Heads move ahead of the sequence and S is padded to the tiles (padded
+    keys are masked, padded query rows sliced off; their zero dO adds
+    nothing to dk/dv). The forward saves only its output and the per-row
+    logsumexp; the backward recomputes the score tiles in VMEM.
+    ``nn.attention`` decides when a model takes this route.
     """
-    b, sq, h, dh = q.shape
-    kvh = k.shape[2]
-    g = h // kvh
-    if g > 1:
-        idx = jnp.arange(h) // g
-        k = k[:, :, idx, :]
-        v = v[:, :, idx, :]
-    qf = q.transpose(0, 2, 1, 3).reshape(b * h, sq, dh)
-    kf = k.transpose(0, 2, 1, 3).reshape(b * h, k.shape[1], dh)
-    vf = v.transpose(0, 2, 1, 3).reshape(b * h, v.shape[1], dh)
-    pad = (-dh) % 128
-    if pad:
-        qf = jnp.pad(qf, ((0, 0), (0, 0), (0, pad)))
-        kf = jnp.pad(kf, ((0, 0), (0, 0), (0, pad)))
-        vf = jnp.pad(vf, ((0, 0), (0, 0), (0, pad)))
-        # zero-padding k changes q.k by nothing (zeros), v-padding adds zero
-        # columns sliced off below; but the softmax scale must use the REAL dh
-    out = flash_attention_tiled(qf, kf, vf, causal=causal, window=window,
-                                bq=bq, bk=bk, scale=dh ** -0.5,
-                                interpret=INTERPRET)
-    out = out[..., :dh]
-    return out.reshape(b, h, sq, dh).transpose(0, 2, 1, 3)
+    return _flash(q, k, v, causal, window, _attention_mxu_dtype())

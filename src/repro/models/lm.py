@@ -33,7 +33,7 @@ from repro.models.blocks import (
     init_block_cache,
     init_block_state,
 )
-from repro.nn.attention import init_attention
+from repro.nn.attention import init_attention, layers_per_call
 from repro.nn.norms import apply_norm, init_norm
 from repro.utils.scopes import EMBED, HEAD, scoped
 
@@ -183,7 +183,8 @@ def _group_scan(cfg: ModelConfig, gi: int, x, gparams, gstates, gcaches,
     xs = (gparams,
           gstates if with_states else [{}] * n_pat,
           gcaches if with_caches else [{}] * n_pat)
-    x, (ns, nc, aux) = jax.lax.scan(body, x, xs)
+    with layers_per_call(g.repeat):
+        x, (ns, nc, aux) = jax.lax.scan(body, x, xs)
     return x, ns, (nc if with_caches else None), aux
 
 

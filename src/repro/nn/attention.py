@@ -5,10 +5,13 @@ Shapes: hidden (B, S, d); heads laid out (B, S, H, Dh). GQA repeats each of
 the KVH key/value heads across G = H // KVH query heads via a reshape —
 no materialized repetition.
 
-Long-sequence prefill/train uses :func:`chunked_attention` — an online-
-softmax scan over KV chunks that never materializes the (S, S) score matrix
-(the pure-JAX analogue of the Pallas flash kernel in repro/kernels; XLA maps
-it to a fori loop with O(S * chunk) live memory).
+Full-sequence attention (training, cache-less prefill) picks its route in
+:func:`attention_route`: on TPU the Pallas flash attention with its own
+backward (``kernels.ops.flash_attention``, no (S, S) tensor in HBM);
+elsewhere :func:`dense_attention`, or above ``chunked_threshold``
+:func:`chunked_attention` — an online-softmax scan over KV chunks that never
+materializes the (S, S) score matrix (XLA maps it to a fori loop with
+O(S * chunk) live memory), and the kernel's oracle off TPU.
 
 Sliding-window layers keep a ROLLING cache of ``window`` slots: absolute
 position p lives in slot p % W; slot validity and relative distance are
@@ -18,6 +21,8 @@ O(W) compute and memory regardless of sequence length — this is what makes
 """
 from __future__ import annotations
 
+import collections
+import contextlib
 from typing import NamedTuple
 
 import jax
@@ -26,6 +31,7 @@ import jax.numpy as jnp
 from repro.config import ModelConfig
 from repro.distributed.sharding import MeshPolicy, shard
 from repro.api import bind, plan_of, role_treated
+from repro.kernels import ops
 from repro.nn.rotary import apply_rope
 from repro.utils.scopes import ATTENTION, scoped
 
@@ -212,6 +218,67 @@ def chunked_attention(q, k, v, *, causal: bool, window: int = 0,
     o = acc / jnp.maximum(l[..., None], 1e-30)
     o = jnp.moveaxis(o.reshape(b, kvh * g, sq, dh), 1, 2)
     return o.astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Route of the full-sequence branch
+# ---------------------------------------------------------------------------
+
+# The flash kernel's route starts at this many tokens: below it dense
+# attention's forward and backward are faster on a TPU v5e (PERF.md, timed
+# at qwen2-0.5b widths).
+FLASH_MIN_SEQ = 512
+# head dims the kernel is compiled for in tests/test_tpu_compile.py
+FLASH_HEAD_DIMS = (64, 128)
+
+# trace-time tally of the route each full-sequence call took; a call in a
+# scanned layer group counts once per layer of the group
+_ROUTES: collections.Counter = collections.Counter()
+_LAYERS_PER_CALL = [1]
+
+
+def attention_routes() -> dict[str, int]:
+    """{route: calls} traced since the last :func:`reset_attention_routes`;
+    route is "flash", "dense" or "chunked"."""
+    return dict(_ROUTES)
+
+
+def reset_attention_routes() -> None:
+    _ROUTES.clear()
+
+
+@contextlib.contextmanager
+def layers_per_call(n: int):
+    """Count each route traced inside as ``n`` calls: a scan body traced
+    once stands for ``n`` layers."""
+    _LAYERS_PER_CALL.append(_LAYERS_PER_CALL[-1] * n)
+    try:
+        yield
+    finally:
+        _LAYERS_PER_CALL.pop()
+
+
+def _partitioned_by_xla(policy: MeshPolicy | None) -> bool:
+    """A sharding policy outside shard_map leaves the partitioning to
+    GSPMD, which cannot partition a Mosaic call; inside shard_map (every
+    mesh axis manual) each device runs its own shard."""
+    if policy is None:
+        return False
+    mesh = jax.sharding.get_abstract_mesh()
+    return not (mesh.axis_names
+                and set(mesh.manual_axes) == set(mesh.axis_names))
+
+
+def attention_route(sq: int, sk: int, head_dim: int, *,
+                    chunked_threshold: int = 2048,
+                    policy: MeshPolicy | None = None) -> str:
+    """Which attention the full-sequence branch takes, from shapes, the
+    backend (``ops.INTERPRET``) and the sharding."""
+    if (not ops.INTERPRET and sq == sk and sq >= FLASH_MIN_SEQ
+            and head_dim in FLASH_HEAD_DIMS
+            and not _partitioned_by_xla(policy)):
+        return "flash"
+    return "chunked" if sq > chunked_threshold else "dense"
 
 
 # ---------------------------------------------------------------------------
@@ -462,7 +529,8 @@ def apply_attention(p: dict, x: jax.Array, cfg: ModelConfig, *,
     """Attention sublayer (projections + core + output projection).
 
     Modes:
-      - train:   cache None            -> full (chunked) attention over x
+      - train:   cache None            -> full attention over x, by
+                 :func:`attention_route` (flash kernel, dense or chunked)
       - prefill: cache given, S > 1    -> token-parallel forward over the
                  whole prompt; K/V for ALL positions written to the cache in
                  one pass (``pos`` = offset of x[_, 0], normally 0;
@@ -523,7 +591,13 @@ def apply_attention(p: dict, x: jax.Array, cfg: ModelConfig, *,
         # NOTE: no explicit q/k head-dim constraints — H / KVH are often not
         # divisible by the model axis (GQA); GSPMD propagates from the
         # projection outputs without forcing an involuntary reshard.
-        if sq > chunked_threshold:
+        route = attention_route(sq, sq, dh,
+                                chunked_threshold=chunked_threshold,
+                                policy=policy)
+        _ROUTES[route] += _LAYERS_PER_CALL[-1]
+        if route == "flash":
+            o = ops.flash_attention(q, k, v, causal=causal, window=window)
+        elif route == "chunked":
             o = chunked_attention(q, k, v, causal=causal, window=window)
         else:
             o = dense_attention(q, k, v, causal=causal, window=window)

@@ -1,11 +1,17 @@
 """Attention module invariants: chunked==dense, rolling cache correctness,
-decode==prefill consistency, flash-decode partials."""
+decode==prefill consistency, flash-decode partials, the flash kernel's
+route and its gradients."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
+from jax.sharding import Mesh, PartitionSpec as P
 
+from repro.distributed.sharding import MeshPolicy
+from repro.kernels import ops
 from repro.nn.attention import (
     KVCache,
+    attention_route,
     cache_update,
     chunked_attention,
     decode_attention,
@@ -96,3 +102,94 @@ def test_flash_decode_partials_match_dense():
     got = ag / lg[..., None]
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5,
                                atol=1e-5)
+
+
+# bf16 keeps 8 significant bits: one rounding of an operand moves it by at
+# most U relative
+U = 2.0 ** -8
+
+
+@pytest.mark.parametrize("mxu", ["float32", "bfloat16"], ids=["f32", "bf16"])
+@pytest.mark.parametrize(
+    "s,h,kvh,causal,window,dtype",
+    [(256, 7, 1, True, 0, jnp.float32),      # G=7, two tiles each way
+     (200, 2, 2, True, 0, jnp.float32),      # G=1, ragged: padded to 256
+     (256, 2, 2, True, 96, jnp.float32),     # windowed
+     (200, 14, 2, True, 48, jnp.bfloat16),   # G=7, windowed, ragged
+     (256, 14, 2, True, 0, jnp.bfloat16),
+     (200, 4, 2, False, 0, jnp.float32),
+     (256, 4, 2, False, 0, jnp.float32)],
+    ids=["g7", "g1-ragged", "window", "g7-window-ragged-bf16", "g7-bf16",
+         "bidirectional-ragged", "bidirectional"])
+def test_flash_matches_dense_and_chunked(monkeypatch, s, h, kvh, causal,
+                                         window, dtype, mxu):
+    """The flash route's output and its dq/dk/dv (jax.grad of a scalar
+    loss) against dense attention and the chunked scan, head_dim 64, at
+    128-token tiles (interpret mode). ``mxu`` "f32" is the kernel as it runs
+    off TPU, held to f32 rounding; "bf16" casts every matmul operand to
+    bf16, as on TPU. That, or bf16 inputs, is held to 4 roundings of U
+    relative to the largest reference value: a score takes two rounded
+    operands, the combine a rounded probability and a rounded value (with
+    bf16 inputs: the cotangent, the saved output and the result)."""
+    monkeypatch.setattr(ops, "FLASH_BLOCK", 128)
+    mxu_dtype = jnp.dtype(mxu)
+    monkeypatch.setattr(ops, "_attention_mxu_dtype", lambda: mxu_dtype)
+    q, k, v = (x.astype(dtype) for x in _qkv(1, s, h, kvh, 64))
+    w = jax.random.normal(jax.random.fold_in(KEY, 3), (1, s, h, 64))
+
+    def value_and_grads(attn):
+        def loss(q, k, v):
+            return jnp.sum(attn(q, k, v).astype(jnp.float32) * w)
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))(q, k, v)
+
+    kw = dict(causal=causal, window=window)
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    got = (ops.flash_attention(q, k, v, **kw),
+           *value_and_grads(lambda *a: ops.flash_attention(*a, **kw))[1])
+    assert [x.dtype for x in got] == [dtype] * 4
+    refs = [dense_attention] + ([chunked_attention] if s > 128 else [])
+    for ref in refs:
+        attn = (lambda q, k, v, ref=ref: ref(q.astype(jnp.float32),
+                                             k.astype(jnp.float32),
+                                             v.astype(jnp.float32), **kw))
+        want = (attn(*f32), *value_and_grads(attn)[1])
+        for name, a, b in zip(["o", "dq", "dk", "dv"], got, want):
+            a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+            tol = 4 * U if jnp.bfloat16 in (dtype, mxu_dtype) else 1e-5
+            err = np.abs(a - b).max() / np.abs(b).max()
+            assert err <= tol, (ref.__name__, name, err / U)
+
+
+def _inside_shard_map(fn):
+    mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
+    out = []
+    jax.jit(jax.shard_map(lambda x: (out.append(fn()), x)[1], mesh=mesh,
+                          in_specs=P("data"), out_specs=P("data")))(
+        jnp.ones(1))
+    return out[0]
+
+
+@pytest.mark.parametrize(
+    "kernels,sq,sk,dh,policy,where,route",
+    [(True, 2048, 2048, 64, None, "jit", "flash"),       # the train cell
+     (True, 8192, 8192, 64, None, "jit", "flash"),       # past 2048 too
+     (True, 2048, 2048, 128, None, "jit", "flash"),
+     (True, 197, 197, 64, None, "jit", "dense"),         # ViT-Base
+     (True, 448, 1500, 64, None, "jit", "dense"),        # cross-attention
+     (True, 2048, 2048, 80, None, "jit", "dense"),       # head dim
+     (True, 2048, 2048, 64, MeshPolicy(), "jit", "dense"),  # GSPMD
+     (True, 2048, 2048, 64, MeshPolicy(), "shard_map", "flash"),
+     (False, 2048, 2048, 64, None, "jit", "dense"),      # off TPU
+     (False, 8192, 8192, 64, None, "jit", "chunked")],
+    ids=["cell", "long", "dh128", "vit", "cross", "dh80", "gspmd",
+         "shard-map", "cpu", "cpu-long"])
+def test_attention_route(monkeypatch, kernels, sq, sk, dh, policy, where,
+                         route):
+    """The full-sequence route, from shapes, backend and sharding alone."""
+    monkeypatch.setattr(ops, "INTERPRET", not kernels)
+
+    def pick():
+        return attention_route(sq, sk, dh, policy=policy)
+
+    assert (_inside_shard_map(pick) if where == "shard_map"
+            else pick()) == route

@@ -12,7 +12,9 @@ import pytest
 
 import repro.configs as configs
 from repro.config import TrainConfig
+from repro.kernels import ops
 from repro.models.lm import init_lm, init_lm_states, lm_loss
+from repro.nn import attention
 from repro.train.step import make_train_state, make_train_step
 from repro.utils import scopes
 
@@ -50,6 +52,59 @@ def op_names():
     text = step.lower(state, {"tokens": tok, "labels": tok}).compile() \
         .as_text()
     return set(OP_NAME.findall(text))
+
+
+CUSTOM_CALL = re.compile(r'tpu_custom_call.*kernel_name = "(\w+)".*'
+                         r"loc\(#(loc\d+)\)")
+LOC = re.compile(r'^#(loc\d+) = loc\("([^"]*)"', re.M)
+
+
+@pytest.fixture(scope="module")
+def cell_step():
+    """The train cell's step (qwen2-0.5b, 4 x 2048, float32 weights, ASI
+    states, block remat) traced with the kernel route on and lowered for a
+    TPU: (route tally, [(kernel name, location name)] of its Mosaic
+    calls). Nothing compiles; the TPU library is not loaded."""
+    cfg = configs.get("qwen2-0.5b").replace(remat="block", dtype="float32")
+    tcfg = TrainConfig(optimizer="adamw", lr=1e-3, clip_norm=2.0, steps=8,
+                       checkpoint_every=0)
+    b, s = 4, 2048
+    state = jax.eval_shape(
+        lambda k: make_train_state(
+            k, init_lm(k, cfg, jnp.float32), cfg, tcfg,
+            asi_states=init_lm_states(k, cfg, b, s, jnp.float32)),
+        jax.random.PRNGKey(0))
+    tok = jax.ShapeDtypeStruct((b, s), jnp.int32)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "INTERPRET", False)
+        attention.reset_attention_routes()
+        traced = jax.jit(make_train_step(lm_loss, cfg, tcfg)).trace(
+            state, {"tokens": tok, "labels": tok})
+        routes = attention.attention_routes()
+        text = traced.lower(lowering_platforms=("tpu",)).as_text(
+            debug_info=True)
+    locs = dict(LOC.findall(text))
+    calls = [(name, locs[loc]) for name, loc in CUSTOM_CALL.findall(text)]
+    return routes, calls
+
+
+def test_attention_routes_count_the_cell_step(cell_step):
+    """All 24 layers of the scanned group take the flash kernel."""
+    routes, _ = cell_step
+    assert routes == {"flash": 24}
+
+
+@pytest.mark.parametrize("kernel", ["flash_attention_fwd",
+                                    "flash_attention_dq",
+                                    "flash_attention_dkv"])
+def test_flash_calls_carry_attention_scope(cell_step, kernel):
+    """The kernel's forward (in the forward and its remat re-run) and its
+    backward custom calls sit under attn.core, where
+    attention_share.train reads them."""
+    _, calls = cell_step
+    names = [loc for name, loc in calls if name == kernel]
+    assert len(names) == (2 if kernel == "flash_attention_fwd" else 1)
+    assert all(scopes.ATTENTION in _parts(loc) for loc in names), names
 
 
 def test_vocabulary_is_distinct():

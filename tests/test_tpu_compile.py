@@ -26,11 +26,14 @@ import repro.configs as configs
 from repro import api
 from repro.config import TrainConfig
 from repro.kernels import ops
+from repro.kernels.flash_attention import (flash_attention_bwd,
+                                           flash_attention_fwd)
 from repro.kernels.lowrank import lowrank_bwd_tiled, lowrank_fused_tiled
 from repro.kernels.ops import _bwd_fits_vmem
 from repro.kernels.qr import choleskyqr_tiled
 from repro.kernels.quant import lowrank_q8_tiled
 from repro.models.lm import init_lm, lm_loss
+from repro.nn.attention import FLASH_HEAD_DIMS, FLASH_MIN_SEQ
 from repro.train.step import (dp_batch_sharding, dp_state_shardings,
                               make_train_state, make_train_step)
 
@@ -147,6 +150,29 @@ def test_int8_lowrank_compiles_at_decode(one_chip, plan):
                ((k,), jnp.float32), ((k, o), jnp.int8), ((o,), jnp.float32))
 
 
+@pytest.mark.parametrize("dh", FLASH_HEAD_DIMS)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("pass_", ["fwd", "bwd"])
+def test_flash_attention_compiles_at_the_train_cell_shape(one_chip, dh,
+                                                          dtype, pass_):
+    # qwen2-0.5b's heads at the train cell's 4 x 2048, causal, at the
+    # tiles ops.flash_attention picks for 2048 tokens; the head dim is the
+    # block's whole last dim, 64 unpadded
+    b, h, kvh, s = 4, 14, 2, 2048
+    s_pad, bq, bk = ops._flash_tiles(s)
+    kw = dict(causal=True, window=0, s_valid=s, bq=bq, bk=bk,
+              mxu_dtype=jnp.bfloat16, interpret=False)
+    qs, kvs = ((b, h, s_pad, dh), dtype), ((b, kvh, s_pad, dh), dtype)
+    row = ((b, h, 1, s_pad), jnp.float32)
+    if pass_ == "fwd":
+        compile_on(one_chip, lambda q, k, v: flash_attention_fwd(
+            q, k, v, **kw), qs, kvs, kvs)
+    else:
+        compile_on(one_chip, lambda q, k, v, do, lse, di: flash_attention_bwd(
+            q, k, v, do, lse, di, **kw), qs, kvs, kvs, qs, row, row)
+
+
 def test_dp_wsi_train_step_compiles_on_four_chips(four_chips, monkeypatch):
     # the DP step is one shard_map program over the mesh; XLA cannot
     # partition a Mosaic kernel, so the fused kernels must sit inside the
@@ -175,3 +201,12 @@ def test_dp_wsi_train_step_compiles_on_four_chips(four_chips, monkeypatch):
     finally:
         api.uninstall(cfg)
     assert "tpu_custom_call" in text
+    # at 512 tokens the per-device body takes the flash kernel, forward and
+    # backward, each call under the attention scope
+    assert s >= FLASH_MIN_SEQ
+    for kernel in ("flash_attention_fwd", "flash_attention_dq",
+                   "flash_attention_dkv"):
+        calls = [ln for ln in text.splitlines()
+                 if "custom-call(" in ln and kernel in ln]
+        assert calls, kernel
+        assert all("attn.core/" in ln for ln in calls), kernel
