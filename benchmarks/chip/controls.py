@@ -7,14 +7,14 @@ many seeds, the control's, and the planted faults', in one process.
 
 Training cells need no measured window: each seed drives the program's
 step through the checked steps (with a fault planted where asked:
-``unchanged``, a step that returns its state; ``half_batch``, a step that
-sees half the rows) and compares it with the float32 reference;
-``control:<fp8|bf16>`` puts the reference computed in that precision (fp8:
-e4m3 forward, e5m2 backward, per-tensor scaled) in the program's place.
-Serving cells run a short window at the cell's own load,
-then read the served tokens' widest gap under the float32 reference and,
-for ``control``, the widest gap of the tokens that the float8 reference
-puts first at the same positions.
+``unchanged``, a step that returns its state; ``half_batch``, a step
+whose mean is over half the rows, the other half replaced by the first)
+and compares it with the float32 reference; ``control:<fp8|bf16>`` puts
+the reference computed in that precision (fp8: e4m3 forward, e5m2
+backward, per-tensor scaled) in the program's place. Serving cells run a
+short window at the cell's own load, then read the served tokens' widest
+gap under the float32 reference and, for ``control``, the widest gap of
+the tokens that the float8 reference puts first at the same positions.
 
 The benchmark's own runs never run this. Prints one JSON line per seed
 and reading.
@@ -39,8 +39,8 @@ def train_readings(w: dict, seed: int, what: list[str]) -> dict:
 
     import jax
 
-    tr = harness.driver("train")
     cfg, mix = w["cfg"], w["mix"]
+    tr = harness.driver(mix["kind"])
     out = {}
     ref = tr.reference_numbers(cfg, mix, seed)
     for kind in what:
@@ -51,7 +51,7 @@ def train_readings(w: dict, seed: int, what: list[str]) -> dict:
         else:
             fault = None if kind == "program" else kind
             _, state, step = tr.build(cfg, mix, seed, fault)
-            pool = tr.draw_pool(seed, mix, cfg["model"]["vocab_size"])
+            pool = tr.draw_pool(seed, cfg, mix)
             state, got = tr.program_numbers(cfg, mix, seed, state, step, pool)
             jax.block_until_ready(state)
             del state, step, pool
@@ -62,7 +62,7 @@ def train_readings(w: dict, seed: int, what: list[str]) -> dict:
 
 
 def serve_readings(w: dict, seed: int, what: list[str], seconds: float):
-    sv = harness.driver("serve_open_loop")
+    sv = harness.driver(w["mix"]["kind"])
     out = {}
     for kind in ("program", "alter_token"):
         if kind not in what:

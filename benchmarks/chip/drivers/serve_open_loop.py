@@ -84,7 +84,7 @@ def build(cfg: dict, mix: dict, seed: int):
 
     mcfg = model.model_config(cfg)
     plan = model.install_plan(cfg, mcfg)
-    params = model.draw_params(seed, model.params_template(mcfg))
+    params = model.draw_params(seed, cfg, mcfg)
     e = mix["engine"]
     return ServeEngine(params, mcfg, plan=plan, max_slots=e["max_slots"],
                        max_cache=e["max_cache"], paged=True,
@@ -194,7 +194,7 @@ def reference_gaps(cfg: dict, seed: int, picked, pad_to: int,
     from reference import qwen2
 
     mcfg = model.model_config(cfg)
-    params = model.draw_params(seed, model.params_template(mcfg))
+    params = model.draw_params(seed, cfg, mcfg)
     fn = jax.jit(lambda p, t: qwen2.hidden("f32", cfg, p, t)[0])
     fn_c = jax.jit(lambda p, t: qwen2.hidden(prec, cfg, p, t)[0])
     worst, n = 0.0, 0

@@ -12,14 +12,19 @@ holds no second set of weights on the device). The same state then runs
 the window, cycling through the pool, with at most two steps in flight.
 
 A traffic file of this kind holds ``batch``, ``seq``, ``pool`` (batches
-drawn before the window), ``check_steps`` and ``optimizer``.
+drawn before the window), ``check_steps`` and ``optimizer``. The
+configuration's family (``families/``) gives the loss and what a batch
+holds; its ``reference`` key names the plain reference,
+``reference/<name>.py`` (default ``qwen2``).
 """
 from __future__ import annotations
 
 import gc
+import importlib
 
 import harness
 import model
+
 
 def tcfg_of(opt: dict):
     from repro.config import TrainConfig
@@ -30,21 +35,32 @@ def tcfg_of(opt: dict):
                        steps=1 << 30, checkpoint_every=0)
 
 
-def draw_pool(seed: int, mix: dict, vocab: int):
-    """``pool`` batches of uniformly drawn tokens, each row different,
-    labels the next token; made on the device before the window."""
+def reference(cfg: dict):
+    """The configuration's plain reference module."""
+    return importlib.import_module(
+        "reference." + cfg.get("reference", "qwen2"))
+
+
+def draw_pool(seed: int, cfg: dict, mix: dict):
+    """``pool`` batches drawn from the seed, made on the device before the
+    window."""
     import jax
-    import jax.numpy as jnp
 
-    b, s, n = mix["batch"], mix["seq"], mix["pool"]
+    fam = model.family(cfg)
+    pool = jax.jit(lambda k: fam.draw_batches(cfg, mix, mix["pool"], k))(
+        harness.jax_key(seed, 3))
+    return [jax.tree.map(lambda x: x[i], pool) for i in range(mix["pool"])]
 
-    @jax.jit
-    def draw(key):
-        t = jax.random.randint(key, (n, b, s + 1), 0, vocab, jnp.int32)
-        return {"tokens": t[..., :-1], "labels": t[..., 1:]}
 
-    pool = draw(harness.jax_key(seed, 3))
-    return [jax.tree.map(lambda x: x[i], pool) for i in range(n)]
+def batch_template(cfg: dict, mix: dict):
+    """One batch's shapes, as ``jax.ShapeDtypeStruct``s."""
+    import jax
+
+    fam = model.family(cfg)
+    one = jax.eval_shape(lambda k: fam.draw_batches(cfg, mix, 1, k),
+                         jax.random.PRNGKey(0))
+    return jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype),
+                        one)
 
 
 def job_config(cfg: dict, mix: dict) -> dict:
@@ -55,31 +71,76 @@ def job_config(cfg: dict, mix: dict) -> dict:
     return dict(cfg, program=dict(cfg["program"], dtype=mix["param_dtype"]))
 
 
-def build(cfg: dict, mix: dict, seed: int, fault: str | None = None):
-    """The program's train state and jitted step for this cell and seed."""
-    import jax
+def _program(cfg: dict, mix: dict):
+    """(job configuration, ``ModelConfig``, ``TrainConfig``, step function)
+    with the plan installed: what ``build`` and ``lowered`` share."""
+    from repro.train.step import make_train_step
 
     cfg = job_config(cfg, mix)
-    from repro.models.lm import lm_loss
-    from repro.train.step import make_train_state, make_train_step
-
     mcfg = model.model_config(cfg)
     model.install_plan(cfg, mcfg, batch=mix["batch"], seq=mix["seq"])
     tcfg = tcfg_of(mix["optimizer"])
-    params = model.draw_params(seed, model.params_template(mcfg))
-    states = model.draw_states(
-        seed, model.states_template(mcfg, mix["batch"], mix["seq"]))
-    state = make_train_state(jax.random.PRNGKey(0), params, mcfg, tcfg,
-                             asi_states=states)
-    step = make_train_step(lm_loss, mcfg, tcfg)
+    step = make_train_step(model.family(cfg).loss(cfg), mcfg, tcfg)
+    return cfg, mcfg, tcfg, step
+
+
+def _jit(step):
+    import jax
+
+    return jax.jit(step, donate_argnums=0)
+
+
+def build(cfg: dict, mix: dict, seed: int, fault: str | None = None):
+    """The program's train state and jitted step for this cell and seed."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.train.step import make_train_state
+
+    cfg, mcfg, tcfg, step = _program(cfg, mix)
+    params = model.draw_params(seed, cfg, mcfg)
+    states = model.draw_states(seed, model.states_template(
+        cfg, mcfg, mix["batch"], mix["seq"]))
+    if mcfg.wasi.project:
+        # project mode starts from a truncated SVD of every scoped weight;
+        # on a TPU that SVD compiles for minutes in every process, so the
+        # program makes its state on the host's CPU device, moved after
+        cpu = jax.devices("cpu")[0]
+        with jax.default_device(cpu):
+            state = make_train_state(jax.random.PRNGKey(0),
+                                     jax.device_put(params, cpu), mcfg, tcfg,
+                                     asi_states=jax.device_put(states, cpu))
+        state = jax.device_put(state, jax.devices()[0])
+    else:
+        state = make_train_state(jax.random.PRNGKey(0), params, mcfg, tcfg,
+                                 asi_states=states)
+    del params, states
     if fault == "unchanged":
         inner = step
         step = lambda s, b: (s, inner(s, b)[1])
     elif fault == "half_batch":
+        # the second half of the rows replaced by the first: the mean is
+        # taken over half the batch, and every shape stays as it was
         inner = step
-        step = lambda s, b: inner(
-            s, jax.tree.map(lambda x: x[:x.shape[0] // 2], b))
-    return mcfg, state, jax.jit(step, donate_argnums=0)
+        step = lambda s, b: inner(s, jax.tree.map(
+            lambda x: jnp.concatenate([x[:x.shape[0] // 2]] * 2), b))
+    return mcfg, state, _jit(step)
+
+
+def lowered(cfg: dict, mix: dict):
+    """The step ``build`` jits for this configuration and traffic, lowered
+    on ``jax.ShapeDtypeStruct``s (no weights on the device)."""
+    import jax
+
+    from repro.train.step import make_train_state
+
+    cfg, mcfg, tcfg, step = _program(cfg, mix)
+    state = jax.eval_shape(
+        lambda p, s: make_train_state(jax.random.PRNGKey(0), p, mcfg, tcfg,
+                                      asi_states=s),
+        model.params_template(cfg, mcfg),
+        model.states_template(cfg, mcfg, mix["batch"], mix["seq"]))
+    return _jit(step).lower(state, batch_template(cfg, mix))
 
 
 def program_numbers(cfg: dict, mix: dict, seed: int, state, step, pool):
@@ -87,8 +148,7 @@ def program_numbers(cfg: dict, mix: dict, seed: int, state, step, pool):
     and the numbers the reference checks."""
     import jax
 
-    from reference.qwen2 import leaf_norms
-
+    leaf_norms = reference(cfg).leaf_norms
     b1 = mix["optimizer"]["beta1"]
     p0 = jax.device_get(state.params)
     losses, grad = [], None
@@ -100,18 +160,16 @@ def program_numbers(cfg: dict, mix: dict, seed: int, state, step, pool):
             mu = state.opt.mu
             grad = jax.device_get(leaf_norms(
                 jax.tree.map(lambda m: m / (1 - b1), mu)))
-    change = host_change_norms(state.params, p0)
+    change = host_change_norms(state.params, p0, leaf_norms)
     del p0
     return state, {"losses": losses, "grad": grad, "change": change}
 
 
-def host_change_norms(params, p0) -> dict:
+def host_change_norms(params, p0, leaf_norms) -> dict:
     """``leaf_norms`` of ``params - p0``, with ``p0`` on the host, taken
     on the host in float32."""
     import jax
     import numpy as np
-
-    from reference.qwen2 import leaf_norms
 
     flat, tree = jax.tree_util.tree_flatten(params)
     diffs = [np.asarray(jax.device_get(a), np.float32)
@@ -123,16 +181,15 @@ def host_change_norms(params, p0) -> dict:
 def reference_numbers(cfg: dict, mix: dict, seed: int, prec: str = "f32"):
     """The plain reference's numbers for the same seed, from weights and
     states it draws itself."""
-    from reference import qwen2
-
+    ref = reference(cfg)
     cfg = job_config(cfg, mix)
     mcfg = model.model_config(cfg)
-    params = model.draw_params(seed, model.params_template(mcfg))
-    states = model.draw_states(
-        seed, model.states_template(mcfg, mix["batch"], mix["seq"]))
-    pool = draw_pool(seed, mix, cfg["model"]["vocab_size"])
-    return qwen2.train_reference(prec, cfg, mix["optimizer"], params, states,
-                                 pool[:mix["check_steps"]])
+    params = model.draw_params(seed, cfg, mcfg)
+    states = model.draw_states(seed, model.states_template(
+        cfg, mcfg, mix["batch"], mix["seq"]))
+    pool = draw_pool(seed, cfg, mix)
+    return ref.train_reference(prec, cfg, mix["optimizer"], params, states,
+                               pool[:mix["check_steps"]])
 
 
 def compare(prog: dict, ref: dict, limits: dict, *, skip_share: float):
@@ -182,7 +239,7 @@ def run(w: dict, seed: int, seconds: float, trace_dir, t_start: float,
 
     cfg, mix = w["cfg"], w["mix"]
     mcfg, state, step = build(cfg, mix, seed, fault)
-    pool = draw_pool(seed, mix, cfg["model"]["vocab_size"])
+    pool = draw_pool(seed, cfg, mix)
     jax.block_until_ready((state, pool))
     peak_built = harness.memory_peak_bytes(w["chips"])
     state, prog = program_numbers(cfg, mix, seed, state, step, pool)

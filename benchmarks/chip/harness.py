@@ -5,7 +5,35 @@ A cell (an entry of ``workloads`` in ``BENCHMARK.json``) names a
 configuration file under ``configs/`` and a traffic file under
 ``traffic/``. The traffic file's ``kind`` names the driver module under
 ``drivers/`` that runs it; each per-layer metric is read by
-``metrics/<name>.py``. Nothing here changes when a cell is added.
+``metrics/<name>.py``; the cell's limits are ``limits/<cell>.json``.
+Nothing here changes when a cell is added.
+
+A configuration file holds ``name``, ``model`` (the published config's
+keys), ``wasi`` (the method's settings), ``plan`` (the ranks the program
+must resolve) and ``program``, with ``dtype``, ``norm``, ``mlp_act``,
+``remat`` and ``block``. Whatever is particular to its model it says in
+keys of its own, each optional, whose absence gives a decoder language
+model of one uniform layer kind checked against ``reference/qwen2.py``:
+
+* ``program.family``: the module ``families/<family>.py`` (``lm``, the
+  default, or ``vit`` with ``program.n_classes``): the program's
+  parameter and state templates and loss, what a batch holds, and a
+  step's operations;
+* ``program.groups``: ``[{"pattern": [kind, ...], "repeat": n}, ...]`` in
+  place of one group of ``program.block``; the layers must sum to
+  ``model.num_hidden_layers``;
+* ``program.ssm``, ``program.moe``: the program's ``SsmConfig`` and
+  ``MoeConfig``;
+* ``program.fields``: other ``ModelConfig`` fields by name (a name that
+  is no field is an error);
+* ``program.parts``: the parts (``attention``, ``mlp``, ``mamba2``) of a
+  layer kind that ``flops.PARTS`` lacks;
+* ``init``: {leaf name or path tail: rule} for leaves the default rules
+  draw wrongly (``model._by_rule`` lists the rules);
+* ``reference``: the plain reference, ``reference/<name>.py``, with
+  ``train_reference`` and ``leaf_norms`` (default ``qwen2``);
+* ``scopes``: scope names the configuration's program sets beyond
+  ``scopes.SCOPES``, read into the cell's table of device-time shares.
 """
 from __future__ import annotations
 

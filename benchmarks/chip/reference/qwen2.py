@@ -237,10 +237,11 @@ def loss(prec, cfg, params, tokens, labels, states, v_chunk=256):
     return tot / (b * n), new
 
 
-def leaf_norms(tree, xp=jnp) -> dict:
-    """Norm of every weight leaf, the stacked layer leaves one per layer:
-    {path: vector of norms}. ``xp`` is ``jnp`` (in float32, on the device)
-    or ``numpy`` (in the leaves' own type, on the host)."""
+def leaf_norms(tree, xp=jnp, stacked: str = "groups") -> dict:
+    """Norm of every weight leaf, the leaves stacked over layers (under
+    ``stacked``) one per layer: {path: vector of norms}. ``xp`` is ``jnp``
+    (in float32, on the device) or ``numpy`` (in the leaves' own type, on
+    the host)."""
     out = {}
     flat = jax.tree_util.tree_flatten_with_path(tree)[0]
     for path, x in flat:
@@ -248,7 +249,7 @@ def leaf_norms(tree, xp=jnp) -> dict:
                         for k in path)
         if xp is jnp:
             x = x.astype(F32)
-        if name.startswith("groups"):
+        if name.startswith(stacked):
             out[name] = xp.sqrt(xp.sum(x * x, axis=tuple(range(1, x.ndim))))
         else:
             out[name] = xp.sqrt(xp.sum(x * x))[None]

@@ -5,18 +5,20 @@ The program sets a ``jax.named_scope`` where each layer's work happens
 ``metadata={op_name=".../<scope>/..."}``. A TPU trace's ops carry their HLO
 instruction names but not that metadata, so the two are joined here:
 
-* the map: the same ``make_train_step(lm_loss, ...)`` ``drivers/train.py``
-  jits (``donate_argnums=0``), lowered on abstract arguments (no weights
-  on the device) and compiled through the persistent cache the run has
-  filled; its ``Compiled.as_text()`` gives {instruction name: op_name}.
-  Built once per cell and process, whichever reader asks first;
+* the map: the step the cell's driver jits, as its ``lowered(cfg, mix)``
+  gives it on abstract arguments (no weights on the device), compiled
+  through the persistent cache the run has filled; its
+  ``Compiled.as_text()`` gives {instruction name: op_name}. Built once
+  per cell and process, whichever reader asks first;
 * the attribution: every op of the step program (``XLA Modules`` events
   named ``jit_step(...)``) inside ``Trace.window``, each interval clipped
   to the window, loops, conditionals and calls left out (their bodies'
   ops are counted themselves, as in ``Trace.top_ops``). An op goes to the
-  innermost scope of :data:`SCOPES` on its op_name path, to ``other`` on
+  innermost scope of the cell's table on its op_name path, to ``other`` on
   none, and to ``unmapped`` when its name is not in the map. A fusion
-  carries the metadata XLA gives it, that of its root.
+  carries the metadata XLA gives it, that of its root. The table is
+  :data:`SCOPES`, the program's base scope names, and those a
+  configuration's ``scopes`` key adds for its own layers.
 
 A share is a bucket's seconds over all the step program's op seconds in
 the window, in percent. No share is read when ``unmapped`` passes 1 % of
@@ -31,7 +33,8 @@ import sys
 import harness
 import trace_reduce
 
-# the yardstick: the program's scope names (tests hold the two lists equal)
+# the yardstick: the program's base scope names (tests hold each in the
+# program's list); a configuration adds its own under ``scopes``
 SCOPES = ("wasi.asi_upkeep", "wasi.matmul", "wasi.grad", "attn.core",
           "lm.embed", "lm.head", "lm.xent", "train.optimizer",
           "train.wsi_refresh")
@@ -62,40 +65,25 @@ def names_of(text: str) -> dict:
     return out
 
 
-def scope_of(path: str) -> str:
-    """The innermost scope of :data:`SCOPES` on an op_name path."""
+def table(cfg: dict) -> tuple:
+    """The scope names a cell of this configuration reads: :data:`SCOPES`
+    and the configuration's own ``scopes``."""
+    return SCOPES + tuple(s for s in cfg.get("scopes", ()) if s not in SCOPES)
+
+
+def scope_of(path: str, scopes: tuple = SCOPES) -> str:
+    """The innermost scope of ``scopes`` on an op_name path."""
     for part in reversed(path.split("/")):
         while (m := TRANSFORMED.match(part)):
             part = m.group(1)
-        if part in SCOPES:
+        if part in scopes:
             return part
     return OTHER
 
 
 def lowered_step(cfg: dict, mix: dict):
-    """The step ``drivers/train.py`` jits for this configuration and
-    traffic, lowered on ``jax.ShapeDtypeStruct``s."""
-    import jax
-    import jax.numpy as jnp
-
-    import model
-    from repro.models.lm import lm_loss
-    from repro.train.step import make_train_state, make_train_step
-
-    train = harness.driver("train")
-    cfg = train.job_config(cfg, mix)
-    mcfg = model.model_config(cfg)
-    model.install_plan(cfg, mcfg, batch=mix["batch"], seq=mix["seq"])
-    tcfg = train.tcfg_of(mix["optimizer"])
-    state = jax.eval_shape(
-        lambda p, s: make_train_state(jax.random.PRNGKey(0), p, mcfg, tcfg,
-                                      asi_states=s),
-        model.params_template(mcfg),
-        model.states_template(mcfg, mix["batch"], mix["seq"]))
-    tokens = jax.ShapeDtypeStruct((mix["batch"], mix["seq"]), jnp.int32)
-    batch = {"tokens": tokens, "labels": tokens}
-    step = jax.jit(make_train_step(lm_loss, mcfg, tcfg), donate_argnums=0)
-    return step.lower(state, batch)
+    """The step the cell's driver jits, lowered on abstract arguments."""
+    return harness.driver(mix["kind"]).lowered(cfg, mix)
 
 
 def _compile_without_cache(cfg: dict, mix: dict):
@@ -135,7 +123,7 @@ def step_map(cfg: dict, mix: dict) -> tuple[dict, dict]:
         jax.monitoring.unregister_event_listener(on_event)
     info = {"compile_s": harness.now() - t0, "cache_hit": bool(hits),
             "recompiled": False}
-    if not any(scope_of(p) != OTHER for p in names.values()):
+    if not any(scope_of(p, table(cfg)) != OTHER for p in names.values()):
         names = names_of(_compile_without_cache(cfg, mix).as_text())
         info.update(recompiled=True, compile_s=harness.now() - t0)
     info["instructions"] = len(names)
@@ -144,12 +132,12 @@ def step_map(cfg: dict, mix: dict) -> tuple[dict, dict]:
 
 
 def attribute(trace, names: dict, program: str = PROGRAM,
-              missing: dict | None = None) -> dict:
+              missing: dict | None = None, scopes: tuple = SCOPES) -> dict:
     """Seconds of the step program's ops in the trace's window, by bucket
-    (every scope, ``other``, ``unmapped``); the seconds of each unmapped
-    name go to ``missing`` if given."""
+    (every scope of ``scopes``, ``other``, ``unmapped``); the seconds of
+    each unmapped name go to ``missing`` if given."""
     lo, hi = trace.window
-    out = dict.fromkeys(SCOPES + (OTHER, UNMAPPED), 0.0)
+    out = dict.fromkeys(scopes + (OTHER, UNMAPPED), 0.0)
     for o in trace.ops_in("", program):
         if o.name.split(" ")[1:2] in trace_reduce.CONTAINERS:
             continue
@@ -157,7 +145,7 @@ def attribute(trace, names: dict, program: str = PROGRAM,
         if dt <= 0:
             continue
         name = o.label.partition(" = ")[0].strip().lstrip("%")
-        bucket = scope_of(names[name]) if name in names else UNMAPPED
+        bucket = scope_of(names[name], scopes) if name in names else UNMAPPED
         out[bucket] += dt * 1e-9 / trace.devices
         if bucket == UNMAPPED and missing is not None:
             missing[name] = missing.get(name, 0.0) + dt * 1e-9
@@ -180,7 +168,8 @@ def shares(ctx) -> dict | None:
         return None
     names, info = step_map(ctx["cfg"], ctx["mix"])
     missing: dict = {}
-    secs = attribute(trace, names, missing=missing)
+    secs = attribute(trace, names, missing=missing,
+                     scopes=table(ctx["cfg"]))
     total = sum(secs.values())
     if total <= 0:
         return None

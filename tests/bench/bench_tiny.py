@@ -49,6 +49,33 @@ def tiny(name: str, hidden: int = 64) -> dict:
     return w
 
 
+def tiny_vit(hidden: int = 64) -> dict:
+    """The ``vit-finetune-wasi`` cell as the harness reads it, at a test's
+    size: two layers of width ``hidden`` with 4 heads and an MLP of 2h,
+    32 x 32 images of 16 patches, 10 classes, 4 images a step; the rank
+    of each MLP site a third of ``hidden`` (rank_frac 0.33). Limits stay
+    as committed."""
+    import harness
+
+    w = copy.deepcopy(harness.cell(harness.load_spec(), "vit-finetune-wasi"))
+    cfg, h, k = w["cfg"], hidden, round(0.33 * hidden)
+    cfg["model"].update(hidden_size=h, intermediate_size=2 * h,
+                        num_hidden_layers=2, num_attention_heads=4,
+                        image_size=32, patch_size=8)
+    cfg["program"]["n_classes"] = 10
+    cfg["init"]["head/w"] = {"rule": "normal", "std": h ** -0.5}
+
+    def site(i, o, rank=0, bias=False):
+        return {"in": i, "out": o, "rank": rank, "bias": bias}
+
+    cfg["plan"] = {"sites": {
+        "attn/wq": site(h, h, bias=True), "attn/wk": site(h, h, bias=True),
+        "attn/wv": site(h, h, bias=True), "attn/wo": site(h, h),
+        "mlp/up": site(h, 2 * h, k), "mlp/down": site(2 * h, h, k)}}
+    w["mix"].update(batch=4, seq=17, pool=4)
+    return w
+
+
 # An open-loop serving mix at a test's size. The benchmark holds no serving
 # cell until its traffic rests on a published trace; these tests keep the
 # serving driver and its check sound for the cell that will use them.
@@ -82,6 +109,30 @@ def tiny_serve(hidden: int = 64) -> dict:
          "mix": copy.deepcopy(SERVE_MIX), "limits": dict(SERVE_LIMITS),
          "end_to_end": [], "per_layer": []}
     return _shrink(w, hidden)
+
+
+def synthetic_trace(names: dict, extra: int = 0):
+    """One ``jit_step`` program running one 10 ns op per mapped name, plus
+    ``extra`` ops of names outside the map, a loop op over all of them, and
+    an op of another program."""
+    from trace_reduce import Event, Trace, op_key
+
+    t = Trace(devices=1)
+    ops, at = [], 100
+    labels = [f"%{n} = f32[2]{{0}} fusion(f32[2]{{0}} %p)" for n in names]
+    labels += [f"%stranger.{i} = f32[2]{{0}} add(f32[2]{{0}} %p)"
+               for i in range(extra)]
+    for lab in labels:
+        ops.append(Event(op_key(lab), at, at + 10, lab))
+        at += 10
+    loop = "%while.9 = (f32[2]{0}) while((f32[2]{0}) %t), body=%b"
+    ops.append(Event(op_key(loop), 100, at, loop))
+    t.ops = ops + [Event("%x add", at + 20, at + 30, "%x = f32[] add()")]
+    t.modules = [Event("jit_step(1)", 90, at + 5),
+                 Event("jit_other(2)", at + 15, at + 35)]
+    t.spans = [Event("bench.train_dispatch", 95, at + 40)]
+    t.window = (95, at + 40)
+    return t
 
 
 def no_disk_cache(monkeypatch) -> None:

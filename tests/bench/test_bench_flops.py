@@ -50,3 +50,25 @@ def test_unknown_device_kind_raises():
     with pytest.raises(KeyError, match="no published peaks"):
         flops.peaks("TPU v9 imaginary")
     assert flops.peaks("TPU v5 lite")["bf16_flops"] == 197e12
+
+
+def _lm_token_flops_before(cfg: dict, ctx: float) -> float:
+    """``flops.lm_token_flops`` as it counted before layer parts: every
+    plan site in every layer, causal attention, the head."""
+    m = cfg["model"]
+    per_layer = sum(flops.linear_flops(s) for s in cfg["plan"]["sites"].values())
+    attn = 2 * 2 * m["num_attention_heads"] * m["head_dim"] * ctx
+    head = 2 * m["hidden_size"] * cfg["plan"]["padded_vocab"]
+    return m["num_hidden_layers"] * (per_layer + attn) + head
+
+
+@pytest.mark.parametrize("ctx", [1.0, 1024.5, 2048.0, 777.25])
+def test_qwen2_count_is_bit_for_bit_as_before(ctx):
+    import harness
+
+    w = harness.cell(harness.load_spec(), "qwen2-train-wasi")
+    cfg, mix = w["cfg"], w["mix"]
+    assert flops.lm_token_flops(cfg, ctx) == _lm_token_flops_before(cfg, ctx)
+    seq = mix["seq"]
+    before = 3 * mix["batch"] * seq * _lm_token_flops_before(cfg, (seq + 1) / 2)
+    assert flops.train_step_flops(cfg, mix["batch"], seq) == before

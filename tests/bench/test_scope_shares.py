@@ -7,11 +7,11 @@ from pathlib import Path
 import pytest
 
 sys.path.insert(0, str(Path(__file__).resolve().parent))
-from bench_tiny import tiny  # noqa: E402
+from bench_tiny import synthetic_trace, tiny  # noqa: E402
 
 import harness  # noqa: E402
 import scopes  # noqa: E402
-from trace_reduce import CONTAINERS, Event, Trace, op_key, reduce  # noqa: E402
+from trace_reduce import CONTAINERS, reduce  # noqa: E402
 
 FIXTURE = Path(__file__).resolve().parent / "probe.xplane.pb"
 READERS = {"asi_upkeep_share.train": ("wasi.asi_upkeep",),
@@ -30,9 +30,18 @@ def _fresh(monkeypatch):
 
 
 def test_scopes_are_the_programs():
+    """Every scope the program sets is read: it is in the base list or in
+    some configuration file's ``scopes``; and every base scope is one the
+    program sets."""
+    import json
+
     from repro.utils import scopes as program
 
-    assert scopes.SCOPES == program.ALL
+    declared = set(scopes.SCOPES)
+    for f in (harness.HERE / "configs").glob("*.json"):
+        declared |= set(json.loads(f.read_text()).get("scopes", ()))
+    assert set(program.ALL) <= declared
+    assert set(scopes.SCOPES) <= set(program.ALL)
 
 
 def test_parser_gives_the_innermost_scope():
@@ -92,44 +101,22 @@ def test_attribution_on_the_recorded_trace():
     assert got["other"] > 0 and got["unmapped"] > 0
 
 
-def _synthetic(names: dict, extra: int = 0):
-    """One ``jit_step`` program running one 10 ns op per mapped name, plus
-    ``extra`` ops of names outside the map, a loop op over all of them, and
-    an op of another program."""
-    t = Trace(devices=1)
-    ops, at = [], 100
-    labels = [f"%{n} = f32[2]{{0}} fusion(f32[2]{{0}} %p)" for n in names]
-    labels += [f"%stranger.{i} = f32[2]{{0}} add(f32[2]{{0}} %p)"
-               for i in range(extra)]
-    for lab in labels:
-        ops.append(Event(op_key(lab), at, at + 10, lab))
-        at += 10
-    loop = "%while.9 = (f32[2]{0}) while((f32[2]{0}) %t), body=%b"
-    ops.append(Event(op_key(loop), 100, at, loop))
-    t.ops = ops + [Event("%x add", at + 20, at + 30, "%x = f32[] add()")]
-    t.modules = [Event("jit_step(1)", 90, at + 5),
-                 Event("jit_other(2)", at + 15, at + 35)]
-    t.spans = [Event("bench.train_dispatch", 95, at + 40)]
-    t.window = (95, at + 40)
-    return t
-
-
 def test_reader_is_silent_when_more_than_1pct_is_unmapped(monkeypatch):
     names = {f"fusion.{i}": "jit(step)/jvp(step)/attn.core/exp"
              for i in range(100)}
     monkeypatch.setattr(scopes, "step_map",
                         lambda cfg, mix: (names, {"compile_s": 0.0}))
     reader = _reader("attention_share.train")
-    ctx = {"trace": _synthetic(names, extra=1), "cfg": {}, "mix": {}}
+    ctx = {"trace": synthetic_trace(names, extra=1), "cfg": {}, "mix": {}}
     assert reader.read(ctx) == pytest.approx(100 * 100 / 101)
-    ctx = {"trace": _synthetic(names, extra=2), "cfg": {}, "mix": {}}
+    ctx = {"trace": synthetic_trace(names, extra=2), "cfg": {}, "mix": {}}
     assert reader.read(ctx) is None
 
 
 def test_reader_is_silent_on_a_program_without_scopes(monkeypatch):
     monkeypatch.setitem(sys.modules, "repro.utils.scopes", None)
     monkeypatch.setattr(scopes, "step_map", lambda cfg, mix: 1 / 0)
-    t = _synthetic({"fusion.1": ""})
+    t = synthetic_trace({"fusion.1": ""})
     for name in READERS:
         assert _reader(name).read({"trace": t, "cfg": {}, "mix": {}}) is None
 
@@ -139,7 +126,7 @@ def test_readers_on_the_tiny_cells_compiled_names(capsys):
     names, info = scopes.step_map(w["cfg"], w["mix"])
     assert info["instructions"] == len(names) > 1000
     assert not info["recompiled"]
-    t = _synthetic(names)
+    t = synthetic_trace(names)
     ctx = {"trace": t, "cfg": w["cfg"], "mix": w["mix"], "cell": w}
     count = {}
     for path in names.values():
